@@ -15,13 +15,7 @@ import time
 sys.path.insert(0, os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src")))
 sys.path.insert(0, os.path.abspath(os.path.join(os.path.dirname(__file__), "..")))
 
-# --sharded simulates a pod on this host: force 8 host devices BEFORE any
-# import below can initialize the jax backend (XLA reads the flag once).
-if "--sharded" in sys.argv:
-    _flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in _flags:
-        os.environ["XLA_FLAGS"] = (
-            _flags + " --xla_force_host_platform_device_count=8").strip()
+ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 import functools  # noqa: E402
 import subprocess  # noqa: E402
@@ -29,8 +23,23 @@ from datetime import datetime, timezone  # noqa: E402
 
 from benchmarks import common  # noqa: E402
 from benchmarks import paper_figures as F  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
-ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+_FORCE_HOST_DEVICES = "--xla_force_host_platform_device_count=8"
+
+
+def _simulate_pod_off_tpu() -> None:
+    """``--sharded`` without a TPU simulates a pod: re-exec this process
+    with 8 forced host devices (XLA reads the flag once, when the backend
+    starts). On a TPU the chips are the devices and nothing is forced."""
+    import jax
+
+    flags = os.environ.get("XLA_FLAGS", "")
+    if jax.default_backend() == "tpu" or _FORCE_HOST_DEVICES in flags:
+        return
+    os.environ["XLA_FLAGS"] = f"{flags} {_FORCE_HOST_DEVICES}".strip()
+    os.execv(sys.executable, [sys.executable] + sys.orig_argv[1:])
+
 # Repo-root records the bench functions (re)write; every run APPENDS the
 # fresh record to results/bench/history.jsonl with a timestamp, so the
 # BENCH_*.json numbers gain a trajectory instead of being overwritten.
@@ -139,6 +148,9 @@ def main() -> None:
                     help="with --tune: smallest cutout + tightest budget "
                          "(the ci.sh tune tier)")
     args = ap.parse_args()
+    if args.sharded:
+        _simulate_pod_off_tpu()
+    enable_compile_cache(ROOT)
 
     if args.quick:
         benches = [("search_runtime", lambda: F.bench_search_runtime(quick=True))]

@@ -61,6 +61,18 @@ def _rescore(x, rows, queries):
     return jnp.where(rows >= 0, s, -jnp.inf)
 
 
+def eager() -> bool:
+    """True outside every jit / shard_map / vmap trace.
+
+    Host-orchestrated code (the eager fused driver, host spans) may run only
+    then. Testing ``queries`` for a `jax.core.Tracer` is not enough: under
+    jit / shard_map the index arrays may be traced while the queries are a
+    closed-over concrete array, and the eager driver's ``np.asarray`` of a
+    traced mask would fail.
+    """
+    return jax.core.trace_ctx.is_top_level()
+
+
 VALID_MODES = ("two_phase", "progressive")
 VALID_VERIFICATIONS = ("fused", "batched", "scan")
 
@@ -192,7 +204,7 @@ def search(arrays: IndexArrays, meta: IndexMeta, queries,
     # Host spans only make sense OUTSIDE an ambient trace (inside one they
     # would time jaxpr construction, not work — DESIGN.md §14); the check is
     # shared with the fused-driver routing below.
-    clean = jax.core.trace_state_clean()
+    clean = eager()
     active = clean and (cfg.obs or _trace.enabled())
     with _span("search", active=active, metric="search.batch_us") as sp_e2e:
         if cfg.mode == "progressive":
@@ -294,8 +306,7 @@ def search_segments(snap, queries, cfg: RuntimeConfig = RuntimeConfig()):
                           else 0), meta.n_pad)
     ids_b, scores_b, stats = search(snap.arrays, meta, q,
                                     dataclasses.replace(cfg, k=k_base))
-    active = ((cfg.obs or _trace.enabled())
-              and jax.core.trace_state_clean())
+    active = (cfg.obs or _trace.enabled()) and eager()
     with _span("segments_merge", active=active,
                metric="search.merge_us") as sp:
         ids, scores = _merge_segments(snap.base_alive, stats.rows, ids_b,
@@ -312,5 +323,5 @@ def search_segments(snap, queries, cfg: RuntimeConfig = RuntimeConfig()):
     )
 
 
-__all__ = ["RuntimeConfig", "SearchStats", "next_pow2", "search",
+__all__ = ["RuntimeConfig", "SearchStats", "eager", "next_pow2", "search",
            "search_segments"]

@@ -131,9 +131,12 @@ def block_valid_from_ids(ids, page_rows: int, xp=jnp):
 
     Derived from ids rather than stored so tombstoning/sharding layers that
     rewrite ids (padding rows carry -1) stay consistent automatically.
+    Spelled as a max, not ``any(ids >= 0)``: at 3-row pages and NB = 208k
+    the TPU compiler takes ~30 s on that boolean reduce, under 1 s on the
+    max.
     """
     nb = ids.shape[0] // page_rows
-    return xp.any(ids.reshape(nb, page_rows) >= 0, axis=1)
+    return xp.max(ids.reshape(nb, page_rows), axis=1) >= 0
 
 
 def sketch_margin(queries, sk_err, eps: float, xp=jnp):

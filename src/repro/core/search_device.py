@@ -135,12 +135,20 @@ def blocks_from_radii(arrays: IndexArrays, d_sp, radius):
     over sp ranges, but O(NB * KMAX) instead of an XLA scan over S. Every
     verification backend (fused / batched / scan) goes through this one
     function, so block selections agree across backends by construction.
+
+    The gather runs one table column at a time: a single (B, NB, KMAX)
+    gather takes the TPU compiler minutes at NB = 208k (Yahoo! Music),
+    while KMAX one-column gathers compile in about a second.
     """
     if radius.ndim == 1:
         radius = radius[:, None]
     sel_sp = sc.sphere_select(d_sp, arrays.sp_radius[None, :], radius)
-    gathered = sel_sp[:, jnp.maximum(arrays.block_sp_idx, 0)]  # (B, NB, KMAX)
-    return jnp.any(gathered & (arrays.block_sp_idx >= 0)[None], axis=2)
+    out = jnp.zeros((sel_sp.shape[0], arrays.block_sp_idx.shape[0]), bool)
+    for j in range(arrays.block_sp_idx.shape[1]):              # KMAX, static
+        sp = arrays.block_sp_idx[:, j]
+        out = out | (jnp.take(sel_sp, jnp.maximum(sp, 0), axis=1)
+                     & (sp >= 0)[None, :])
+    return out
 
 
 def select_blocks_batch(arrays: IndexArrays, q_proj, radius):
@@ -169,9 +177,12 @@ def block_priority(arrays: IndexArrays, q_proj):
     ub = (q_proj @ arrays.sp_center.T
           + q_norm[:, None] * arrays.sp_radius[None, :])           # (B, S)
     ub = jnp.max(ub, axis=0)                                       # (S,)
-    gathered = jnp.where(arrays.block_sp_idx >= 0,
-                         ub[jnp.maximum(arrays.block_sp_idx, 0)], -jnp.inf)
-    return jnp.minimum(-jnp.max(gathered, axis=1), jnp.float32(1e30))
+    best = jnp.full(arrays.block_sp_idx.shape[:1], -jnp.inf)       # (NB,)
+    for j in range(arrays.block_sp_idx.shape[1]):   # per column: see
+        sp = arrays.block_sp_idx[:, j]              # `blocks_from_radii`
+        best = jnp.maximum(best, jnp.where(sp >= 0, ub[jnp.maximum(sp, 0)],
+                                           -jnp.inf))
+    return jnp.minimum(-best, jnp.float32(1e30))
 
 
 def truncate_union(union, prio, cap: int):

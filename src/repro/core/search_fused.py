@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels import ops
+from ..kernels import ops, ref
 from ..obs import metrics as _metrics
 from ..obs.trace import span as _span
 from .index import IndexArrays, IndexMeta
@@ -133,9 +133,9 @@ def _verify(arrays: IndexArrays, queries, slots, sel, init_s, init_r, c_half,
     cache = None
     if want_scores:
         # the identical full-matrix product the dense round just consumed
-        # (same (n_pad, d) @ (d, B) orientation as `ref.block_mips_ref`) —
-        # XLA CSEs it with the in-round matmul, so this costs nothing extra
-        cache = (arrays.x @ queries.T).T
+        # (the same expression as `ref.block_mips_ref`) — XLA CSEs it with
+        # the in-round matmul, so this costs nothing extra
+        cache = ref.mips_score_ref(arrays.x, queries, valid).T
     return TopK(scores=top_s, rows=top_r), pages, cand, done_a, cache
 
 

@@ -14,18 +14,18 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+from concurrent.futures import ThreadPoolExecutor
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..obs import trace as _trace
 from ..obs.trace import span as _span
 from .index import IndexArrays, IndexMeta, build_index
-from .runtime import RuntimeConfig
+from .runtime import RuntimeConfig, eager
 from .runtime import search as runtime_search
 
 
@@ -61,17 +61,27 @@ def _pad_to(arr: np.ndarray, n: int, fill):
     return np.pad(arr, width, constant_values=fill)
 
 
+def _build_in_parallel(fn, items: list) -> list:
+    """``[fn(item) for item in items]`` on one thread per item. Shard index
+    builds are independent NumPy work that mostly runs without the GIL, so
+    n shards build in well under n times one shard's time."""
+    with ThreadPoolExecutor(max(len(items), 1)) as pool:
+        return list(pool.map(fn, items))
+
+
 def build_sharded(x: np.ndarray, n_shards: int, **kwargs) -> ShardedIndex:
     n = x.shape[0]
     bounds = np.linspace(0, n, n_shards + 1).astype(int)
-    parts = []
-    for s in range(n_shards):
+
+    def build(s: int):
         lo, hi = bounds[s], bounds[s + 1]
         idx = build_index(x[lo:hi], **kwargs)
         a = idx.arrays._replace(
             ids=np.where(idx.arrays.ids >= 0, idx.arrays.ids + lo, -1).astype(np.int32)
         )
-        parts.append((a, idx.meta))
+        return a, idx.meta
+
+    parts = _build_in_parallel(build, list(range(n_shards)))
 
     n_pad = max(m.n_pad for _, m in parts)
     g_max = max(m.n_groups for _, m in parts)
@@ -148,7 +158,7 @@ def sharded_search(
         mode="progressive", cs_prune=cs_prune, budget=budget)
     cfg = dataclasses.replace(cfg, k=k)
     fn = _sharded_search_fn(meta, k, mesh, axis, cfg)
-    active = jax.core.trace_state_clean() and (cfg.obs or _trace.enabled())
+    active = eager() and (cfg.obs or _trace.enabled())
     with _span("sharded_fanout", active=active,
                metric="sharded.fanout_us") as sp:
         return sp.fence(fn(sharded.arrays, jnp.asarray(queries, jnp.float32)))
@@ -184,11 +194,11 @@ def _sharded_search_fn(meta: IndexMeta, k: int, mesh: Mesh, axis: str,
         return best_i, best_s, pages
 
     in_arr_spec = IndexArrays(**{f: P(axis) for f in IndexArrays._fields})
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(in_arr_spec, P()),
         out_specs=(P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -202,7 +212,9 @@ class MutableShardedProMIPS:
     other shards' immutable bases. Ids past the initial corpus route to the
     last shard (the append range). Search fans out to the per-shard
     segment-merged runtime and merges k x n_shards (id, score) pairs — the
-    same wire economics as `sharded_search`.
+    same wire economics as `sharded_search`. Shard i lives on device
+    ``i % len(jax.devices())``, so on n chips each holds 1/n of the corpus
+    and the per-shard searches run concurrently.
     """
 
     def __init__(self, x: np.ndarray, n_shards: int, *,
@@ -212,12 +224,18 @@ class MutableShardedProMIPS:
 
         n = x.shape[0]
         self.bounds = np.linspace(0, n, n_shards + 1).astype(int)
-        self.shards = [
-            MutableProMIPS(x[lo:hi], ids=np.arange(lo, hi),
-                           delta_capacity=delta_capacity,
-                           auto_compact=auto_compact, **build_kwargs)
-            for lo, hi in zip(self.bounds[:-1], self.bounds[1:])
-        ]
+        self.shards = _build_in_parallel(
+            lambda b: MutableProMIPS(x[b[0]:b[1]], ids=np.arange(b[0], b[1]),
+                                     delta_capacity=delta_capacity,
+                                     auto_compact=auto_compact,
+                                     **build_kwargs),
+            list(zip(self.bounds[:-1], self.bounds[1:])))
+        self._place()
+
+    def _place(self) -> None:
+        devices = jax.devices()
+        for i, shard in enumerate(self.shards):
+            shard.device = devices[i % len(devices)]
 
     @property
     def n_alive(self) -> int:
@@ -260,7 +278,7 @@ class MutableShardedProMIPS:
         computations overlap under JAX's async dispatch.
 
         Returns (ids (B, k), scores (B, k), `ShardedStats`)."""
-        active = jax.core.trace_state_clean() and (
+        active = eager() and (
             _trace.enabled() or (runtime is not None and runtime.obs))
         # the dispatch span is deliberately UNFENCED: fencing each launch
         # would serialize the shards and destroy the async-dispatch overlap
@@ -315,12 +333,13 @@ class MutableShardedProMIPS:
                             if key.startswith(prefix)}
             obj.shards.append(
                 MutableProMIPS.from_state(shard_arrays, meta["shards"][i]))
+        obj._place()
         return obj
 
 
 def device_put_sharded_index(sharded: ShardedIndex, mesh: Mesh, axis: str = "model"):
-    arrays = jax.tree.map(
-        lambda a: jax.device_put(jnp.asarray(a), NamedSharding(mesh, P(axis))),
-        sharded.arrays,
-    )
+    """Ship the stacked host index onto ``mesh``, shard axis over ``axis``.
+    Host arrays go straight to their `NamedSharding`: each device receives
+    only its own slice (no staging of the whole stack on one device)."""
+    arrays = jax.device_put(sharded.arrays, NamedSharding(mesh, P(axis)))
     return ShardedIndex(arrays=arrays, meta=sharded.meta)

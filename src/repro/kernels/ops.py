@@ -18,6 +18,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..obs import metrics as _metrics
 from . import ref
 from .binary_probe import binary_probe_lb as _binary_probe_pallas
 from .block_mips import MAX_K as BLOCK_MIPS_MAX_K
@@ -49,10 +50,14 @@ def block_mips(x, valid, q, slots, sel, init_scores, init_rows, c_half, *,
     Walks ``slots`` pages of ``x`` in place and returns (top_scores (B, k),
     top_rows (B, k), cnt (B, NS), pages (B,), cand (B,)) — see
     `block_mips.block_mips`.  Backend-aware default like `mips_score`;
-    ``k > BLOCK_MIPS_MAX_K`` (streaming over-fetch) always takes the oracle,
-    whose VMEM-free merge has no k cap.
+    ``k > BLOCK_MIPS_MAX_K`` (streaming over-fetch) takes the oracle, whose
+    VMEM-free merge has no k cap — on the Pallas route that detour is
+    counted in ``kernels.block_mips_oracle`` (once per traced program).
     """
-    if not _resolve(use_pallas) or k > BLOCK_MIPS_MAX_K:
+    pallas = _resolve(use_pallas)
+    if pallas and k > BLOCK_MIPS_MAX_K:
+        _metrics.counter("kernels.block_mips_oracle").inc()
+    if not pallas or k > BLOCK_MIPS_MAX_K:
         return ref.block_mips_ref(x, valid, q, slots, sel, init_scores,
                                   init_rows, c_half, k=k, page_rows=page_rows,
                                   dense=dense)

@@ -37,14 +37,14 @@ def block_mips_ref(x, valid, q, slots, sel, init_scores, init_rows, c_half,
         # DMA performs anyway
         xt = jnp.take(x.reshape(-1, page_rows, x.shape[1]), slots,
                       axis=0).reshape(-1, x.shape[1])
-        rvalid = jnp.take(valid.reshape(-1, page_rows), slots,
-                          axis=0).reshape(-1).astype(bool)
-    # (R, d) @ (d, B) then transpose — the same orientation as
-    # `mips_score_ref` (the batched backend's kernel), which the CPU GEMM
-    # executes measurably faster than (B, d) @ (d, R) at R >> B; per-element
-    # dots are the identical reduction, so results are unchanged
-    scores = (xt.astype(jnp.float32)
-              @ q.astype(jnp.float32).T).T                   # (B, R)
+        # row validity stays flat (see the row-mask note in `_verify_core`)
+        rvalid = jnp.take(valid, rows_flat).astype(bool)
+    # Exactly the batched backend's score expression (`mips_score_ref`, then
+    # the transpose): XLA CPU folds a transpose that directly follows a dot
+    # into a (B, d) @ (d, R) product whose sums round differently, so any
+    # other spelling breaks fused-vs-batched bit parity. Invalid rows are
+    # masked again below, so the NEG_INF fill changes nothing.
+    scores = mips_score_ref(xt, q, rvalid).T                 # (B, R)
     return _verify_core(scores, rvalid, sel, init_scores, init_rows, c_half,
                         rows_flat, k=k, page_rows=page_rows)
 
@@ -60,8 +60,7 @@ def block_mips_cached_ref(scores_full, valid, slots, sel, init_scores,
     rows_flat = (slots.astype(jnp.int32)[:, None] * page_rows
                  + jnp.arange(page_rows, dtype=jnp.int32)).reshape(-1)
     scores = jnp.take(scores_full, rows_flat, axis=1)        # (B, R)
-    rvalid = jnp.take(valid.reshape(-1, page_rows), slots,
-                      axis=0).reshape(-1).astype(bool)
+    rvalid = jnp.take(valid, rows_flat).astype(bool)
     return _verify_core(scores, rvalid, sel, init_scores, init_rows, c_half,
                         rows_flat, k=k, page_rows=page_rows)
 
@@ -83,11 +82,14 @@ def _verify_core(scores, rvalid, sel, init_scores, init_rows, c_half,
               - cnt).astype(jnp.int32)                       # exclusive cumsum
     live = sel & ((n0[:, None] + ex_cum) < k)                # ~done_before
     pages = jnp.sum(live.astype(jnp.int32), axis=1)
-    vcnt = rvalid.reshape(n_slots, page_rows).sum(axis=1).astype(jnp.int32)
-    cand = jnp.sum(live.astype(jnp.int32) * vcnt[None, :], axis=1)
-
-    row_live = (live[:, :, None] & rvalid.reshape(1, n_slots, page_rows))
-    masked = jnp.where(row_live.reshape(b, -1), scores, -jnp.inf)  # (B, R)
+    # Row masks stay flat (B, R), and a slot's liveness reaches its rows by
+    # a gather: at Yahoo size (NS = 208k, 3-row pages) the TPU compiler
+    # spends minutes on any (NS, page_rows)-shaped row mask, and seconds on
+    # this form.
+    row_live = (jnp.take(live, jnp.arange(r) // page_rows, axis=1)
+                & rvalid[None, :])                           # (B, R)
+    cand = jnp.sum(row_live.astype(jnp.int32), axis=1)
+    masked = jnp.where(row_live, scores, -jnp.inf)           # (B, R)
     tile_s, idx = jax.lax.top_k(masked, min(k, masked.shape[1]))
     tile_r = jnp.where(tile_s > -jnp.inf,
                        jnp.take(rows_flat, idx), -1).astype(jnp.int32)

@@ -101,7 +101,6 @@ def run_cell(arch_id: str, shape_name: str, *, multi_pod: bool, mesh=None):
         rec["memory_analysis_error"] = str(e)
     try:
         cost = compiled.cost_analysis()
-        cost = cost[0] if isinstance(cost, (list, tuple)) else cost
         rec["hlo_flops"] = float(cost.get("flops", -1))
         rec["hlo_bytes"] = float(cost.get("bytes accessed", -1))
         rec["cost_raw"] = {k: float(v) for k, v in cost.items()

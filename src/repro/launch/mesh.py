@@ -12,13 +12,10 @@ import jax
 
 
 def make_mesh_compat(shape, axes):
-    """`jax.make_mesh` across jax versions: newer jax wants explicit
-    ``axis_types`` (Auto) for shard_map meshes; jax <= 0.4.x has no
-    ``AxisType`` at all and its meshes are implicitly Auto."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    """`jax.make_mesh` with Auto axes, which shard_map and the sharding
+    rules here expect."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

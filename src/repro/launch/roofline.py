@@ -20,9 +20,9 @@ microbatches:
 Depth group sizes: attn=1 layer, xlstm_7_1=8 layers, zamba2=shared_every
 layers, encdec varies enc/dec separately. The sLSTM time recurrence cannot
 be unrolled (seq_len steps); its FLOPs are added analytically
-(`slstm_correction`). Terms use v5e constants: 197 TF/s bf16, 819 GB/s HBM,
-50 GB/s/link ICI; collective wire-bytes = per-device result bytes x ring
-factor (all-reduce 2x, others 1x).
+(`slstm_correction`). Terms use the v5e row of `PEAKS`: 197 TF/s bf16,
+819 GB/s HBM, 50 GB/s/link ICI; collective wire-bytes = per-device result
+bytes x ring factor (all-reduce 2x, others 1x).
 
   PYTHONPATH=src python -m repro.launch.roofline --all [--out results/roofline]
 """
@@ -42,9 +42,17 @@ from .mesh import make_production_mesh  # noqa: E402
 # NOTE: `.dryrun` also mutates XLA_FLAGS at import; it is imported lazily
 # inside `_cost` so `kernel_cost` importers keep their jax backend as-is.
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+# Per-chip peaks keyed by `jax.Device.device_kind` (source: Google Cloud
+# documentation, "TPU v5e"). A device kind missing here is an error, never a
+# default: a roofline share against another chip's peaks means nothing.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12,      # bf16 FLOP/s
+                    "hbm_bw": 819e9,      # HBM bytes/s
+                    "hbm_bytes": 16e9,    # HBM capacity
+                    "ici_bw": 50e9},      # bytes/s per ICI link
+}
+_V5E = PEAKS["TPU v5 lite"]   # the production-mesh cell analysis below
+PEAK_FLOPS, HBM_BW, ICI_BW = _V5E["flops"], _V5E["hbm_bw"], _V5E["ici_bw"]
 RING_FACTOR = {"all-reduce": 2.0, "all-gather": 1.0, "reduce-scatter": 1.0,
                "all-to-all": 1.0, "collective-permute": 1.0}
 
@@ -66,21 +74,31 @@ def kernel_cost(fn, *args):
     BENCH consumers do not read it as achieved traffic; for measured
     per-stage wall-clock against this bound use the offline cutout runner,
     `repro.tune.cutout.stage_records` (DESIGN.md §15).
+
+    The peaks come from `PEAKS` by the default device's kind. On CPU the
+    record carries no roofline terms (a CPU run has no device peak to be a
+    share of); a TPU kind missing from `PEAKS` raises ``KeyError``.
     """
     jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
     compiled = jitted.lower(*args).compile()
     cost = compiled.cost_analysis()
-    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
     flops = float(cost.get("flops", 0.0))
     nbytes = float(cost.get("bytes accessed", 0.0))
-    t_comp = flops / PEAK_FLOPS
-    t_mem = nbytes / HBM_BW
-    bound = max(t_comp, t_mem)
-    return {"flops": flops, "bytes": nbytes,
-            "t_compute_s": t_comp, "t_memory_s": t_mem,
-            "roofline_s": bound,
-            "bound": "compute" if t_comp >= t_mem else "memory",
-            "static_upper_bound": True}
+    dev = jax.devices()[0]
+    out = {"flops": flops, "bytes": nbytes, "device_kind": dev.device_kind,
+           "static_upper_bound": True}
+    if dev.platform == "cpu":
+        return out
+    if dev.device_kind not in PEAKS:
+        raise KeyError(f"no peak rates for device kind {dev.device_kind!r}; "
+                       f"add it to roofline.PEAKS (known: {sorted(PEAKS)})")
+    peak = PEAKS[dev.device_kind]
+    t_comp = flops / peak["flops"]
+    t_mem = nbytes / peak["hbm_bw"]
+    out.update({"t_compute_s": t_comp, "t_memory_s": t_mem,
+                "roofline_s": max(t_comp, t_mem),
+                "bound": "compute" if t_comp >= t_mem else "memory"})
+    return out
 
 
 def _cost(cfg, shape, mesh, *, microbatches=None):
@@ -95,7 +113,6 @@ def _cost(cfg, shape, mesh, *, microbatches=None):
     from .dryrun import parse_collectives
     compiled = lowered.compile()
     cost = compiled.cost_analysis()
-    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
     coll, _ = parse_collectives(compiled.as_text())
     return {"flops": float(cost.get("flops", 0.0)),
             "bytes": float(cost.get("bytes accessed", 0.0)),

@@ -73,6 +73,11 @@ GLOSSARY: Dict[str, tuple] = {
                                        "score cache (no new matmul)"),
     "fused.verify_retraces": ("gauge", "total verify-jit retraces ever "
                                        "(bounded ring's monotonic count)"),
+    # kernel routing
+    "kernels.block_mips_oracle": ("counter", "programs traced with the fused "
+                                             "verification on the jnp oracle "
+                                             "instead of the Pallas kernel "
+                                             "because k > block_mips.MAX_K"),
     # sharded fan-out
     "sharded.fanout_us": ("histogram", "in-graph shard_map fan-out µs"),
     "sharded.dispatch_us": ("histogram", "host-merge per-shard dispatch µs "

@@ -22,7 +22,6 @@ import threading
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from ..core.index import IndexArrays, IndexMeta, ProMIPSIndex
@@ -65,6 +64,9 @@ class MutableProMIPS:
         self._snap: Optional[Snapshot] = None
         self._next_id = int(gids.max()) + 1 if n else 0
         self.compactor = Compactor(compaction) if auto_compact else None
+        # Device the snapshot arrays are committed to (None = JAX's default
+        # device); a sharded owner pins each shard to its own chip.
+        self.device = None
 
     # -- state plumbing ------------------------------------------------------
     def _set_base(self, base: ProMIPSIndex) -> None:
@@ -315,8 +317,10 @@ class MutableProMIPS:
         with self._lock:
             if self._snap is not None:
                 return self._snap
+            def put(a):
+                return jax.device_put(a, self.device)
             if self._base_dev is None:
-                self._base_dev = jax.tree.map(jnp.asarray, self._base.arrays)
+                self._base_dev = jax.tree.map(put, self._base.arrays)
             d = self._delta
             # ship/score only a pow2-quantized prefix of the delta buffers:
             # O(log capacity) distinct compiled shapes between compactions,
@@ -325,10 +329,10 @@ class MutableProMIPS:
             self._snap = Snapshot(
                 arrays=self._base_dev,
                 meta=self._base.meta,
-                base_alive=jnp.asarray(self._base_alive.copy()),
-                delta_x=jnp.asarray(d.x[:cap_q].copy()),
-                delta_gids=jnp.asarray(d.gids[:cap_q].astype(np.int32)),
-                delta_valid=jnp.asarray(d.alive[:cap_q].copy()),
+                base_alive=put(self._base_alive.copy()),
+                delta_x=put(d.x[:cap_q].copy()),
+                delta_gids=put(d.gids[:cap_q].astype(np.int32)),
+                delta_valid=put(d.alive[:cap_q].copy()),
                 epoch=self._epoch,
                 delta_count=d.count,
                 n_base_dead=self._n_base_dead,
@@ -499,6 +503,7 @@ class MutableProMIPS:
             # restore the saved trigger config, not the class default
             compaction = CompactionConfig(**meta.get("compaction", {}))
         obj.compactor = Compactor(compaction) if auto_compact else None
+        obj.device = None
         return obj
 
 

@@ -26,13 +26,13 @@ def test_int8_psum_shard_map():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.distributed.compression import int8_psum
         from repro.launch.mesh import make_mesh_compat
         mesh = make_mesh_compat((8,), ("data",))
         x = jnp.arange(8 * 16, dtype=jnp.float32).reshape(8, 16) / 40.0
-        f = shard_map(lambda s: int8_psum(s, "data"), mesh=mesh,
-                      in_specs=P("data"), out_specs=P("data"), check_rep=False)
+        f = jax.shard_map(lambda s: int8_psum(s, "data"), mesh=mesh,
+                          in_specs=P("data"), out_specs=P("data"),
+                          check_vma=False)
         got = np.asarray(f(x))
         want = np.broadcast_to(np.asarray(x).sum(0, keepdims=True), (8, 16))
         err = np.abs(got - want).max() / (np.abs(want).max() + 1e-9)

@@ -251,6 +251,25 @@ def test_kernel_cost_static_upper_bound_flag():
     assert cost["static_upper_bound"] is True
 
 
+def test_kernel_cost_peaks_keyed_by_device_kind(monkeypatch):
+    """No roofline share off a chip; an unknown chip is an error, never the
+    v5e peaks by default."""
+    import types
+
+    import jax.numpy as jnp
+    from repro.launch import roofline
+    args = (lambda a, b: a @ b, jnp.ones((8, 8)), jnp.ones((8, 8)))
+    cost = roofline.kernel_cost(*args)
+    assert cost["device_kind"] == "cpu" and "roofline_s" not in cost
+    fake = types.SimpleNamespace(platform="tpu", device_kind="TPU v99")
+    monkeypatch.setattr(roofline.jax, "devices", lambda: [fake])
+    with pytest.raises(KeyError, match="TPU v99"):
+        roofline.kernel_cost(*args)
+    fake.device_kind = "TPU v5 lite"
+    cost = roofline.kernel_cost(*args)
+    assert cost["roofline_s"] == max(cost["t_compute_s"], cost["t_memory_s"])
+
+
 def test_max_probe_groups_caps_table():
     from repro.core.quick_probe import build_group_table, pack_codes_np
     rng = np.random.RandomState(0)
