@@ -968,7 +968,7 @@ def bench_obs(quick: bool = True):
 
     real_sf_span, real_rt_span = sf._span, rt._span
 
-    def null_span(name, active=None, metric=None):
+    def null_span(name, active=None, metric=None, layer=None, **stats):
         return trace._NULL
 
     def set_mode(mode):
@@ -1044,8 +1044,9 @@ def bench_obs(quick: bool = True):
         PHASES = {
             "frontend": ("select_frontend", "compensation"),
             "prefilter": ("prefilter_round1", "prefilter_round2"),
-            "verify": ("plan_tile_round1", "plan_tile_round2",
-                       "verify_round1", "verify_round2"),
+            "verify": ("pull_priority", "pull_mask_round1",
+                       "pull_mask_round2", "plan_tile_round1",
+                       "plan_tile_round2", "verify_round1", "verify_round2"),
             "merge": ("rescore",),
         }
         phases = {ph: float(sum(span_means.get(nm, 0.0) for nm in nms))

@@ -35,6 +35,7 @@ from ..core.promips import ProMIPS
 from ..core.runtime import RuntimeConfig
 from ..core.runtime import search as runtime_search
 from ..core.sharded import MutableShardedProMIPS
+from ..obs.trace import span as _span
 from ..stream.mutable import MutableProMIPS
 from .base import Searcher
 from .registry import register
@@ -83,6 +84,13 @@ def _runtime_from_opts(guarantee: GuaranteeConfig, mode: str,
         cs_prune=bool(cs_prune) if cs_prune is not None else False,
         prefilter=bool(prefilter), prefilter_eps=float(prefilter_eps),
         obs=bool(obs))
+
+
+def _pull_answers(ids, scores, stats, cfg: RuntimeConfig):
+    """The batch's answers and stats totals on the host: the search path's
+    last device -> host pulls, in the ``pull_answers`` span."""
+    with _span("pull_answers", active=cfg.obs or None, layer="pull"):
+        return np.asarray(ids), np.asarray(scores), stats.to_dict()
 
 
 @register
@@ -161,7 +169,7 @@ class PromipsSearcher(Searcher):
             return self._search_host(queries, k, cfg)
         ids, scores, stats = runtime_search(self.pm.arrays, self.pm.meta,
                                             queries, cfg)
-        return np.asarray(ids), np.asarray(scores), stats.to_dict()
+        return _pull_answers(ids, scores, stats, cfg)
 
     @property
     def n(self) -> int:
@@ -255,7 +263,7 @@ class StreamSearcher(_MutableMixin, Searcher):
                 ) -> Tuple[np.ndarray, np.ndarray, dict]:
         cfg = self.runtime if runtime is None else runtime
         ids, scores, stats = self.inner.search(queries, k=k, runtime=cfg)
-        return np.asarray(ids), np.asarray(scores), stats.to_dict()
+        return _pull_answers(ids, scores, stats, cfg)
 
     def flush(self, timeout=None) -> None:
         self.inner.join_compaction(timeout)
@@ -357,7 +365,7 @@ class ShardedSearcher(_MutableMixin, Searcher):
                 ) -> Tuple[np.ndarray, np.ndarray, dict]:
         cfg = self.runtime if runtime is None else runtime
         ids, scores, stats = self.inner.search(queries, k=k, runtime=cfg)
-        return np.asarray(ids), np.asarray(scores), stats.to_dict()
+        return _pull_answers(ids, scores, stats, cfg)
 
     def alive_items(self):
         gids, rows = [], []

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.trace import span as _span
 from ..robust.snapshot import (CorruptSnapshotError, verify_dir,
                                write_atomic_dir)
 from .types import Capabilities, GuaranteeConfig, SearchResult
@@ -82,17 +83,23 @@ class Searcher(abc.ABC):
         dimensionality) are rejected with a ValueError HERE, before the jit
         path — a NaN would otherwise poison every score silently and a shape
         mismatch would surface as a cryptic retrace three layers down.
+
+        With tracing on (or the runtime's ``obs``), the whole call is the
+        ``api_search`` span, the outermost of the batch (DESIGN.md §14).
         """
-        q = self._validate_queries(queries)
-        k = int(self.guarantee.k if k is None else k)
-        if k < 1:
-            raise ValueError(f"k must be a positive int, got {k!r}")
-        t0 = time.perf_counter()
-        ids, scores, stats = self._search(q, k, **opts)
-        stats = dict(stats)
-        stats.setdefault("queries", q.shape[0])
-        stats["wall_time_s"] = time.perf_counter() - t0
-        return SearchResult(ids=ids, scores=scores, stats=stats)
+        runtime = opts.get("runtime") or getattr(self, "runtime", None)
+        with _span("api_search", active=getattr(runtime, "obs", False) or None,
+                   layer="api", metric="search.batch_us"):
+            q = self._validate_queries(queries)
+            k = int(self.guarantee.k if k is None else k)
+            if k < 1:
+                raise ValueError(f"k must be a positive int, got {k!r}")
+            t0 = time.perf_counter()
+            ids, scores, stats = self._search(q, k, **opts)
+            stats = dict(stats)
+            stats.setdefault("queries", q.shape[0])
+            stats["wall_time_s"] = time.perf_counter() - t0
+            return SearchResult(ids=ids, scores=scores, stats=stats)
 
     def _validate_queries(self, queries):
         """Boundary validation shared by every backend (and reused verbatim

@@ -206,7 +206,7 @@ def search(arrays: IndexArrays, meta: IndexMeta, queries,
     # shared with the fused-driver routing below.
     clean = eager()
     active = clean and (cfg.obs or _trace.enabled())
-    with _span("search", active=active, metric="search.batch_us") as sp_e2e:
+    with _span("search", active=active, layer="dispatch") as sp_e2e:
         if cfg.mode == "progressive":
             ids, _, stats = search_batch_progressive(arrays, meta, q,
                                                      k=cfg.k, budget=budget,
@@ -240,8 +240,7 @@ def search(arrays: IndexArrays, meta: IndexMeta, queries,
                                              tile_cap=tile_cap)
         else:
             raise ValueError(f"unknown search mode: {cfg.mode!r}")
-        with _span("rescore", active=active,
-                   metric="search.rescore_us") as sp:
+        with _span("rescore", active=active, layer="dispatch") as sp:
             scores = sp.fence(_rescore(arrays.x, stats.rows, q))
         sp_e2e.fence((ids, scores))
     return ids, scores, stats
@@ -307,7 +306,7 @@ def search_segments(snap, queries, cfg: RuntimeConfig = RuntimeConfig()):
     ids_b, scores_b, stats = search(snap.arrays, meta, q,
                                     dataclasses.replace(cfg, k=k_base))
     active = (cfg.obs or _trace.enabled()) and eager()
-    with _span("segments_merge", active=active,
+    with _span("segments_merge", active=active, layer="dispatch",
                metric="search.merge_us") as sp:
         ids, scores = _merge_segments(snap.base_alive, stats.rows, ids_b,
                                       scores_b, snap.delta_x, snap.delta_gids,

@@ -174,7 +174,8 @@ def _plan_tile(mask: np.ndarray, cap: int, n_blocks: int,
                dense_frac: float = DENSE_FRAC, prio=None):
     """Size one verification tile from the host-side (B, NB) selection.
 
-    Returns (slots (NS,) i32, sel (B, NS) bool, lost (B,) bool, dense) or
+    Returns (slots (NS,) i32, sel (B, NS) bool, lost (B,) bool, dense,
+    union) — ``union`` the number of distinct blocks the batch selected — or
     None when no block is selected (the round is skipped outright — an
     identity on the carried top-k with zero pages/candidates, exactly what
     the batched backend's all-masked tile computes the long way).
@@ -198,7 +199,7 @@ def _plan_tile(mask: np.ndarray, cap: int, n_blocks: int,
     n_batch = mask.shape[0]
     if n_union >= dense_frac * n_blocks and cap >= n_blocks:
         slots = np.arange(n_blocks, dtype=np.int32)
-        return slots, mask, np.zeros(n_batch, bool), True
+        return slots, mask, np.zeros(n_batch, bool), True, n_union
     n_slots = min(next_pow2(n_union), cap)
     ublocks = np.nonzero(union)[0]                  # ascending layout order
     if n_union > n_slots:
@@ -217,7 +218,7 @@ def _plan_tile(mask: np.ndarray, cap: int, n_blocks: int,
     slots[: len(take)] = take
     sel = np.zeros((n_batch, n_slots), bool)
     sel[:, : len(take)] = mask[:, take]
-    return slots, sel, lost, False
+    return slots, sel, lost, False, n_union
 
 
 def search_batch_fused(
@@ -252,7 +253,10 @@ def search_batch_fused(
     ``obs`` activates the per-phase spans and round-shape counters
     (DESIGN.md §14). Off (the default), each phase pays one no-op span
     call; no jit graph differs either way — the instrumentation is pure
-    host code between the same device calls.
+    host code between the same device calls. Each device -> host pull has a
+    ``pull`` span of its own, the tile planning's numpy work a ``plan``
+    span, and each verify round span carries its tile's ``slots`` and the
+    batch's ``union`` of selected blocks.
 
     ``dense_frac`` / ``tile_cap`` are the tuner-promoted tile knobs
     (DESIGN.md §15): ``dense_frac`` moves the dense-path threshold
@@ -270,20 +274,20 @@ def search_batch_fused(
         cap = min(cap, int(tile_cap))
         cap2 = min(cap2, int(tile_cap))
 
-    with _span("select_frontend", active=obs,
-               metric="search.frontend_us") as sp:
+    with _span("select_frontend", active=obs, layer="dispatch") as sp:
         q_proj, q_l2sq, d_sp, r0, probe_ok, c_half, mask0 = _frontend(
             arrays, meta, queries)
         sp.fence(mask0)
     # host-side copy of the shared best-first truncation key (same rule as
     # the batched / in-graph drivers), only when a cap can truncate
-    prio_np = (np.asarray(block_priority(arrays, q_proj))
-               if min(cap, cap2) < n_blocks else None)
+    prio_np = None
+    if min(cap, cap2) < n_blocks:
+        with _span("pull_priority", active=obs, layer="pull"):
+            prio_np = np.asarray(block_priority(arrays, q_proj))
     mask_r1 = mask0
     sk_est = sk_bnd = sk_bvalid = None
     if prefilter:
-        with _span("prefilter_round1", active=obs,
-                   metric="search.prefilter_us") as sp:
+        with _span("prefilter_round1", active=obs, layer="dispatch") as sp:
             mask_r1, sk_est, sk_bnd, sk_bvalid = _prefilter1(
                 arrays, queries, mask0, k, meta.page_rows, prefilter_eps,
                 use_pallas)
@@ -297,27 +301,24 @@ def search_batch_fused(
                rows=jnp.full((n_batch, k), -1, jnp.int32))
 
     scores_cache = None
-    with _span("plan_tile_round1", active=obs, metric="search.plan_us"):
+    with _span("pull_mask_round1", active=obs, layer="pull"):
         mask_np = np.asarray(mask_r1)
-        if obs and prefilter:
-            n_sel = float(np.asarray(mask0).sum())
-            _metrics.gauge("search.prefilter_survivor_frac").set(
-                float(mask_np.sum()) / max(n_sel, 1.0))
+    with _span("plan_tile_round1", active=obs, layer="plan"):
         plan = _plan_tile(mask_np, cap, n_blocks, dense_frac, prio=prio_np)
     if plan is None:
         if obs:
             _metrics.counter("fused.rounds_skipped").inc()
         pages1, cand1, done_a, lost1 = zero, zero, false, false
     else:
-        slots, sel, lost_np, dense = plan
+        slots, sel, lost_np, dense, n_union = plan
         if obs:
             _metrics.counter("fused.rounds_dense" if dense
                              else "fused.rounds_sparse").inc()
         # A dense oracle round scores the whole corpus in place; keep that
         # (B, n_pad) product so the compensation round needs NO new matmul.
         want_scores = dense and not ops._resolve(use_pallas)
-        with _span("verify_round1", active=obs,
-                   metric="search.verify_round_us") as sp:
+        with _span("verify_round1", active=obs, layer="dispatch",
+                   slots=len(slots), union=n_union) as sp:
             top, pages1, cand1, done_a, scores_cache = _verify(
                 arrays, queries, jnp.asarray(slots), jnp.asarray(sel),
                 top.scores, top.rows, c_half, k, meta.page_rows, dense,
@@ -325,30 +326,29 @@ def search_batch_fused(
             sp.fence(top.scores)
         lost1 = jnp.asarray(lost_np)
 
-    with _span("compensation", active=obs,
-               metric="search.compensation_us") as sp:
+    with _span("compensation", active=obs, layer="dispatch") as sp:
         s_k = top.scores[:, k - 1]
         need2, r1, mask1 = _round2(arrays, meta, d_sp, q_l2sq, s_k, r0,
                                    done_a, mask0, norm_adaptive, cs_prune)
         sp.fence(mask1)
     mask_r2 = mask1
     if prefilter:
-        with _span("prefilter_round2", active=obs,
-                   metric="search.prefilter_us") as sp:
+        with _span("prefilter_round2", active=obs, layer="dispatch") as sp:
             mask_r2 = _prefilter2(mask1, sk_est, sk_bnd, sk_bvalid, s_k)
             sp.fence(mask_r2)
 
-    with _span("plan_tile_round2", active=obs, metric="search.plan_us"):
-        plan = _plan_tile(np.asarray(mask_r2), cap2, n_blocks, dense_frac,
-                          prio=prio_np)
+    with _span("pull_mask_round2", active=obs, layer="pull"):
+        mask_np = np.asarray(mask_r2)
+    with _span("plan_tile_round2", active=obs, layer="plan"):
+        plan = _plan_tile(mask_np, cap2, n_blocks, dense_frac, prio=prio_np)
     if plan is None:
         if obs:
             _metrics.counter("fused.rounds_skipped").inc()
         pages2, cand2, lost2 = zero, zero, false
     else:
-        slots, sel, lost_np, dense = plan
-        with _span("verify_round2", active=obs,
-                   metric="search.verify_round_us") as sp:
+        slots, sel, lost_np, dense, n_union = plan
+        with _span("verify_round2", active=obs, layer="dispatch",
+                   slots=len(slots), union=n_union) as sp:
             if scores_cache is not None:
                 if obs:
                     _metrics.counter("fused.rounds_cached").inc()

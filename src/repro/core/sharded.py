@@ -159,7 +159,7 @@ def sharded_search(
     cfg = dataclasses.replace(cfg, k=k)
     fn = _sharded_search_fn(meta, k, mesh, axis, cfg)
     active = eager() and (cfg.obs or _trace.enabled())
-    with _span("sharded_fanout", active=active,
+    with _span("sharded_fanout", active=active, layer="dispatch",
                metric="sharded.fanout_us") as sp:
         return sp.fence(fn(sharded.arrays, jnp.asarray(queries, jnp.float32)))
 
@@ -283,11 +283,11 @@ class MutableShardedProMIPS:
         # the dispatch span is deliberately UNFENCED: fencing each launch
         # would serialize the shards and destroy the async-dispatch overlap
         # this loop exists to create (it times enqueue, not device work)
-        with _span("sharded_dispatch", active=active,
+        with _span("sharded_dispatch", active=active, layer="dispatch",
                    metric="sharded.dispatch_us"):
             launched = [shard.search(queries, k=k, runtime=runtime)
                         for shard in self.shards]
-        with _span("sharded_merge", active=active,
+        with _span("sharded_merge", active=active, layer="dispatch",
                    metric="sharded.merge_us") as sp:
             ids_all = [np.asarray(ids) for ids, _, _ in launched]
             scores_all = [np.asarray(scores) for _, scores, _ in launched]

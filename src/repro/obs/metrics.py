@@ -52,19 +52,10 @@ GLOSSARY: Dict[str, tuple] = {
     "search.pages": ("counter", "logical 4KB pages touched (paper's axis)"),
     "search.candidates": ("counter", "rows scored by verification"),
     "search.exhausted": ("counter", "queries that hit a budget cap"),
-    # per-phase span timings (host-orchestrated fused driver + runtime)
-    "search.batch_us": ("histogram", "end-to-end search() batch wall µs"),
-    "search.frontend_us": ("histogram", "select_frontend span µs"),
-    "search.compensation_us": ("histogram", "Condition-B mask span µs"),
-    "search.prefilter_us": ("histogram", "sketch prefilter round span µs"),
-    "search.plan_us": ("histogram", "host tile planning span µs (includes "
-                                    "the mask device->host pull)"),
-    "search.verify_round_us": ("histogram", "one fused verify round µs"),
-    "search.rescore_us": ("histogram", "shared top-k rescore span µs"),
+    # span timings
+    "search.batch_us": ("histogram", "api_search span µs: one Searcher."
+                                     "search batch, answers on the host"),
     "search.merge_us": ("histogram", "stream segment merge span µs"),
-    "search.prefilter_survivor_frac": ("gauge",
-                                       "blocks surviving the sketch "
-                                       "prefilter / blocks selected"),
     # fused driver round shape + jit-cache health
     "fused.rounds_dense": ("counter", "verify rounds on the dense path"),
     "fused.rounds_sparse": ("counter", "verify rounds on the gathered tile"),

@@ -24,14 +24,24 @@ Design constraints, in order:
    (default 8192) under a lock; a long-lived serve process can leave tracing
    on without unbounded growth. `total()` counts every span ever recorded.
 
+4. **Every span says where it sits.** A span names its ``layer`` (``api``,
+   ``dispatch``, ``plan`` or ``pull`` on the search path) and may carry
+   keyword stats (counts at its boundary, e.g. a verify round's ``slots`` and
+   ``union``). Recording, it also notes its ``parent`` (the enclosing open
+   span on this thread) and a ``batch`` id that the outermost span of a call
+   allocates and every nested span inherits, so one search's spans group.
+
 `export_chrome_trace(path)` writes the standard ``{"traceEvents": [...]}``
 JSON (``ph="X"`` complete events, µs timestamps) that chrome://tracing and
-https://ui.perfetto.dev load directly. With ``annotate=True`` each span also
-enters a `jax.profiler.TraceAnnotation`, so spans line up with XLA events in
-a `jax.profiler.trace()` capture (SNIPPETS.md snippet 3).
+https://ui.perfetto.dev load directly; ``args`` carries the span's fields.
+With ``annotate=True`` each span also enters a
+`jax.profiler.TraceAnnotation` whose keyword stats are the same fields, so
+spans line up with XLA events in a `jax.profiler.trace()` capture (SNIPPETS.md
+snippet 3) and name their layer there.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
@@ -48,6 +58,15 @@ _annotate = False
 _capacity = 8192
 _ring: list = []          # completed span dicts, append order, bounded
 _total = 0                # every span ever recorded (monotonic)
+_batches = itertools.count()   # ids allocated by outermost spans
+
+
+class _Open(threading.local):
+    def __init__(self):
+        self.stack = []   # this thread's open spans, outermost first
+
+
+_open = _Open()
 
 _EPOCH_NS = time.perf_counter_ns()   # trace timestamps are relative to import
 
@@ -98,9 +117,10 @@ def total() -> int:
 
 
 def spans() -> list:
-    """Completed spans (oldest first) as dicts:
-    ``{name, t0_us, dur_us, tid, fenced}``. A snapshot copy — safe to
-    iterate while other threads keep tracing."""
+    """Completed spans (in the order they closed) as dicts:
+    ``{name, t0_us, dur_us, tid, fenced, layer, parent, batch, stats}``
+    (``parent`` is the enclosing span's name or None, ``stats`` a dict). A
+    snapshot copy — safe to iterate while other threads keep tracing."""
     with _lock:
         return list(_ring)
 
@@ -132,20 +152,40 @@ class _NullSpan:
 _NULL = _NullSpan()
 
 
-class _Span:
-    __slots__ = ("name", "metric", "_t0", "_fenced", "_ann")
+def _fields(layer, parent, batch, stats) -> dict:
+    """A span's ``layer``, ``parent``, ``batch`` and stats, Nones left out:
+    its profiler annotation's stats and its Chrome export ``args``."""
+    out = {k: v for k, v in (("layer", layer), ("parent", parent),
+                             ("batch", batch)) if v is not None}
+    out.update(stats)
+    return out
 
-    def __init__(self, name: str, metric: Optional[str]):
+
+class _Span:
+    __slots__ = ("name", "metric", "layer", "stats", "parent", "batch",
+                 "_t0", "_fenced", "_ann")
+
+    def __init__(self, name: str, metric: Optional[str],
+                 layer: Optional[str], stats: dict):
         self.name = name
         self.metric = metric
+        self.layer = layer
+        self.stats = stats
         self._fenced = False
         self._ann = None
 
     def __enter__(self):
+        stack = _open.stack
+        outer = stack[-1] if stack else None
+        self.parent = outer.name if outer is not None else None
+        self.batch = outer.batch if outer is not None else next(_batches)
+        stack.append(self)
         if _annotate:
             try:
                 import jax
-                self._ann = jax.profiler.TraceAnnotation(self.name)
+                self._ann = jax.profiler.TraceAnnotation(
+                    self.name, **_fields(self.layer, self.parent, self.batch,
+                                         self.stats))
                 self._ann.__enter__()
             except Exception:       # profiler backend absent: spans still work
                 self._ann = None
@@ -165,12 +205,17 @@ class _Span:
         t1 = time.perf_counter_ns()
         if self._ann is not None:
             self._ann.__exit__(*exc)
+        _open.stack.remove(self)
         dur_us = (t1 - self._t0) / 1e3
         _record({"name": self.name,
                  "t0_us": (self._t0 - _EPOCH_NS) / 1e3,
                  "dur_us": dur_us,
                  "tid": threading.get_ident(),
-                 "fenced": self._fenced})
+                 "fenced": self._fenced,
+                 "layer": self.layer,
+                 "parent": self.parent,
+                 "batch": self.batch,
+                 "stats": self.stats})
         if self.metric is not None:
             from . import metrics
             metrics.histogram(self.metric).observe(dur_us)
@@ -178,21 +223,25 @@ class _Span:
 
 
 def span(name: str, active: Optional[bool] = None,
-         metric: Optional[str] = None):
+         metric: Optional[str] = None, layer: Optional[str] = None,
+         **stats):
     """Open a span. ``active=None`` follows the global switch; ``True``
     forces recording for this call (the `RuntimeConfig.obs` per-call
     opt-in), ``False`` forces the no-op. ``metric`` names a declared
-    histogram (obs.metrics glossary) fed the span duration in µs."""
+    histogram (obs.metrics glossary) fed the span duration in µs. ``layer``
+    names the span's layer (DESIGN.md §14); keyword ``stats`` are counts at
+    the span's boundary, recorded and annotated with it."""
     if not (_enabled if active is None else active):
         return _NULL
-    return _Span(name, metric)
+    return _Span(name, metric, layer, stats)
 
 
 def export_chrome_trace(path: str) -> str:
     """Write stored spans as Chrome trace-event JSON (Perfetto-loadable).
     Returns ``path``. One ``ph="X"`` complete event per span; ``args``
-    carries the ``fenced`` flag so un-fenced (enqueue-time) spans are
-    distinguishable from honest device timings."""
+    carries the ``fenced`` flag, so un-fenced (enqueue-time) spans are
+    distinguishable from honest device timings, and the span's ``layer``,
+    ``parent``, ``batch`` and stats."""
     recs = spans()
     tids = {}
     events = []
@@ -201,7 +250,9 @@ def export_chrome_trace(path: str) -> str:
         events.append({"name": r["name"], "ph": "X", "pid": 0, "tid": tid,
                        "ts": r["t0_us"], "dur": r["dur_us"],
                        "cat": "repro.obs",
-                       "args": {"fenced": r["fenced"]}})
+                       "args": {"fenced": r["fenced"],
+                                **_fields(r["layer"], r["parent"],
+                                          r["batch"], r["stats"])}})
     doc = {"traceEvents": events, "displayTimeUnit": "ms",
            "otherData": {"exporter": "repro.obs.trace",
                          "span_count": len(events)}}

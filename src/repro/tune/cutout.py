@@ -154,9 +154,9 @@ def stage_records(arrays: IndexArrays, meta: IndexMeta, queries, *,
     top = sf.TopK(scores=jnp.full((n_batch, k), -jnp.inf, jnp.float32),
                   rows=jnp.full((n_batch, k), -1, jnp.int32))
     if plan is not None:
-        slots, sel, _, dense = plan
-        recs["_tile"] = {"n_union": int(np.asarray(mask_r1).any(0).sum()),
-                         "tile_slots": int(len(slots)), "dense": bool(dense)}
+        slots, sel, _, dense, n_union = plan
+        recs["_tile"] = {"n_union": n_union, "tile_slots": int(len(slots)),
+                         "dense": bool(dense)}
         rec("fused_verify_tile", sf._verify, arrays, qj, jnp.asarray(slots),
             jnp.asarray(sel), top.scores, top.rows, c_half, k,
             meta.page_rows, dense, use_pallas, False)

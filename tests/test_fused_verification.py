@@ -107,8 +107,8 @@ def test_plan_tile_pow2_buckets():
         mask[:, np.random.RandomState(n_union).permutation(n_blocks)[:n_union]] = True
         plan = sf._plan_tile(mask, n_blocks, n_blocks)
         assert plan is not None
-        slots, sel, lost, dense = plan
-        assert not lost.any()
+        slots, sel, lost, dense, union = plan
+        assert not lost.any() and union == n_union
         sizes.add((len(slots), dense))
     assert len(sizes) <= int(np.ceil(np.log2(n_blocks))) + 2, sizes
     for ns, dense in sizes:

@@ -1,12 +1,18 @@
 """Observability tier (DESIGN.md §14): span tracer, metrics registry, the
 stats-contract choke point, and end-to-end metric-name resolution after one
 smoke search per backend."""
+import dataclasses
+import glob
 import json
+import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import api
+from repro.api.adapters import PromipsSearcher
 from repro.core.promips import ProMIPS
 from repro.core.runtime import RuntimeConfig
 from repro.core.sharded import MutableShardedProMIPS
@@ -43,6 +49,35 @@ def corpus():
 def pm(corpus):
     x, _ = corpus
     return ProMIPS.build(x, m=8, c=0.9, p=0.6, seed=0, norm_strata=4)
+
+
+@pytest.fixture(scope="module")
+def searcher(corpus):
+    """An API index with the sketch prefilter on: every search-path span
+    runs, round 2 included, and a tile cap below the block count truncates
+    (so the priority pull runs too)."""
+    x, _ = corpus
+    return api.build(x, backend="promips",
+                     guarantee=api.GuaranteeConfig(c=0.9, p0=0.5, k=5),
+                     seed=0, prefilter=True)
+
+
+def capped(searcher, **kw):
+    return dataclasses.replace(searcher.runtime,
+                               tile_cap=searcher.pm.meta.n_blocks // 2, **kw)
+
+
+# every span of the search path, by layer
+SEARCH_SPANS = {
+    "api_search": "api",
+    "search": "dispatch", "select_frontend": "dispatch",
+    "prefilter_round1": "dispatch", "prefilter_round2": "dispatch",
+    "verify_round1": "dispatch", "verify_round2": "dispatch",
+    "compensation": "dispatch", "rescore": "dispatch",
+    "plan_tile_round1": "plan", "plan_tile_round2": "plan",
+    "pull_priority": "pull", "pull_mask_round1": "pull",
+    "pull_mask_round2": "pull", "pull_answers": "pull",
+}
 
 
 # -- span tracer -------------------------------------------------------------
@@ -115,6 +150,132 @@ def test_export_chrome_trace(tmp_path):
     for e in doc["traceEvents"]:
         assert e["ph"] == "X" and "ts" in e and "dur" in e
         assert e["args"]["fenced"] is False
+
+
+def test_span_records_and_chrome_export_carry_layer_parent_batch_stats(
+        tmp_path):
+    trace.enable()
+    for _ in range(2):
+        with trace.span("outer", layer="api"):
+            with trace.span("inner", layer="plan", slots=8, union=5):
+                pass
+    recs = trace.spans()
+    assert [r["name"] for r in recs] == ["inner", "outer"] * 2
+    inner, outer = recs[0], recs[1]
+    assert (inner["layer"], inner["parent"], inner["stats"]) == (
+        "plan", "outer", {"slots": 8, "union": 5})
+    assert (outer["layer"], outer["parent"], outer["stats"]) == ("api", None,
+                                                                 {})
+    # the outermost span allocates the batch id; nested spans inherit it
+    assert inner["batch"] == outer["batch"]
+    assert recs[2]["batch"] == recs[3]["batch"] != outer["batch"]
+    doc = json.load(open(trace.export_chrome_trace(str(tmp_path / "t.json"))))
+    args = [e["args"] for e in doc["traceEvents"]]
+    assert args[0] == {"fenced": False, "layer": "plan", "parent": "outer",
+                       "batch": inner["batch"], "slots": 8, "union": 5}
+    assert args[1] == {"fenced": False, "layer": "api",
+                       "batch": outer["batch"]}
+
+
+def test_profiler_host_plane_carries_every_search_span_with_its_layer(
+        searcher, corpus, tmp_path):
+    """One fused search under `jax.profiler.trace`, spans annotated: each
+    search-path span is a host event whose stats name its layer, all of one
+    batch, the verify rounds with their slot counts."""
+    from jax.profiler import ProfileData
+
+    _, q = corpus
+    rt = capped(searcher)
+    searcher.search(q, runtime=rt)                     # compile outside
+    trace.configure(enabled=True, annotate=True)
+    with jax.profiler.trace(str(tmp_path)):
+        searcher.search(q, runtime=rt)
+    trace.configure(enabled=False, annotate=False)
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    seen = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    stats = dict(e.stats)
+                    if "layer" in stats:
+                        seen[e.name] = stats
+    assert set(seen) == set(SEARCH_SPANS)
+    assert {n: st["layer"] for n, st in seen.items()} == SEARCH_SPANS
+    assert len({st["batch"] for st in seen.values()}) == 1
+    assert "parent" not in seen["api_search"]
+    assert seen["search"]["parent"] == "api_search"
+    assert seen["plan_tile_round1"]["parent"] == "search"
+    for rnd in ("verify_round1", "verify_round2"):
+        assert 0 < seen[rnd]["union"] and 0 < seen[rnd]["slots"]
+
+
+def _pulls(monkeypatch):
+    """Record, for every device -> host copy of a jax array (numpy reads a
+    CPU array through its buffer, anything else through ``_value``), the
+    names of the spans open on this thread at that moment (innermost
+    last)."""
+    from jax._src.array import ArrayImpl
+
+    seen = []
+    value, buffer = ArrayImpl._value, ArrayImpl.__buffer__
+
+    def opened():
+        seen.append([sp.name for sp in trace._open.stack])
+
+    def spy_value(self):
+        opened()
+        return value.fget(self)
+
+    def spy_buffer(self, flags):
+        opened()
+        return buffer(self, flags)
+
+    monkeypatch.setattr(ArrayImpl, "_value", property(spy_value))
+    monkeypatch.setattr(ArrayImpl, "__buffer__", spy_buffer)
+    return seen
+
+
+def test_every_pull_of_the_search_path_is_in_a_pull_span(searcher, corpus,
+                                                         monkeypatch):
+    _, q = corpus
+    searcher.search(q, runtime=capped(searcher))       # compile outside
+    pulls = _pulls(monkeypatch)
+    searcher.search(q, runtime=capped(searcher))
+    n_off = len(pulls)
+    pulls.clear()
+    searcher.search(q, runtime=capped(searcher, obs=True))
+    # obs adds no pull of its own, and removes none
+    assert len(pulls) == n_off >= 4
+    for open_spans in pulls:
+        assert open_spans and open_spans[-1].startswith("pull_"), open_spans
+        assert not any(n.startswith("plan_") for n in open_spans)
+    assert {s[-1] for s in pulls} == {"pull_priority", "pull_mask_round1",
+                                      "pull_mask_round2", "pull_answers"}
+
+
+def test_verify_round_stats_equal_plan_tile_result(searcher, corpus,
+                                                   monkeypatch):
+    _, q = corpus
+    plans = []
+    plan_tile = sf._plan_tile
+
+    def spy(mask, *a, **kw):
+        plan = plan_tile(mask, *a, **kw)
+        plans.append((int(mask.any(axis=0).sum()), plan))
+        return plan
+
+    monkeypatch.setattr(sf, "_plan_tile", spy)
+    trace.enable()
+    for rt in (searcher.runtime, capped(searcher)):
+        searcher.search(q, runtime=rt)
+    rounds = [r for r in trace.spans() if r["name"].startswith("verify_round")]
+    assert len(rounds) == len(plans) == 4
+    for rec, (union, plan) in zip(rounds, plans):
+        slots, _, _, _, n_union = plan
+        assert n_union == union
+        assert rec["stats"] == {"slots": len(slots), "union": union}
 
 
 # -- metrics registry --------------------------------------------------------
@@ -232,18 +393,18 @@ def test_metrics_resolve_after_one_smoke_search_per_backend(pm, corpus):
     shd = MutableShardedProMIPS(x, 2, m=8, c=0.9, p=0.6, seed=0)
     _, _, st = shd.search(qj, k=5)                             # sharded
     st.to_dict()
+    PromipsSearcher(pm, RuntimeConfig()).search(q, k=5)        # API facade
 
     snap = metrics.snapshot()
     assert set(snap) <= set(metrics.GLOSSARY), \
         sorted(set(snap) - set(metrics.GLOSSARY))
     required = {"search.queries", "search.pages", "search.candidates",
-                "search.exhausted", "search.batch_us", "search.frontend_us",
-                "search.verify_round_us", "search.rescore_us",
-                "sharded.dispatch_us", "sharded.merge_us", "search.merge_us",
+                "search.exhausted", "search.batch_us", "sharded.dispatch_us",
+                "sharded.merge_us", "search.merge_us",
                 "fused.verify_retraces"}
     assert required <= set(snap), sorted(required - set(snap))
     assert snap["search.queries"] > 0
-    assert snap["search.batch_us"]["count"] > 0
+    assert snap["search.batch_us"]["count"] == 1     # the one API call
 
 
 # -- bounded VERIFY_TRACES ring ----------------------------------------------
@@ -318,13 +479,17 @@ def test_runtime_config_obs_validation():
 def test_obs_toggle_is_bit_identical_and_records(pm, corpus):
     _, q = corpus
     qj = jnp.asarray(q, jnp.float32)
-    ids_off, scores_off, _ = pm.search(qj, k=5, verification="fused",
-                                       norm_adaptive=True, cs_prune=True)
+    kw = dict(k=5, verification="fused", norm_adaptive=True, cs_prune=True)
+    ids_off, scores_off, _ = pm.search(qj, **kw)
     assert trace.spans() == []   # obs off: nothing recorded
-    ids_on, scores_on, _ = pm.search(qj, k=5, verification="fused",
-                                     norm_adaptive=True, cs_prune=True,
-                                     obs=True)
+    n_traces = sf.VERIFY_TRACES.total
+    ids_on, scores_on, _ = pm.search(qj, obs=True, **kw)
+    # obs compiles no program of its own: the verify jits do not retrace
+    assert sf.VERIFY_TRACES.total == n_traces
     assert np.array_equal(np.asarray(ids_off), np.asarray(ids_on))
     assert np.array_equal(np.asarray(scores_off), np.asarray(scores_on))
     names = {s["name"] for s in trace.spans()}
     assert {"search", "select_frontend", "verify_round1"} <= names
+    n_spans = trace.total()
+    pm.search(qj, **kw)
+    assert sf.VERIFY_TRACES.total == n_traces and trace.total() == n_spans
