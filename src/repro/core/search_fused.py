@@ -255,8 +255,9 @@ def search_batch_fused(
     call; no jit graph differs either way — the instrumentation is pure
     host code between the same device calls. Each device -> host pull has a
     ``pull`` span of its own, the tile planning's numpy work a ``plan``
-    span, and each verify round span carries its tile's ``slots`` and the
-    batch's ``union`` of selected blocks.
+    span, and each verify round span carries its tile's ``slots``, the
+    batch's ``union`` of selected blocks and the kernel's grid ``steps``
+    (0 on the oracle route).
 
     ``dense_frac`` / ``tile_cap`` are the tuner-promoted tile knobs
     (DESIGN.md §15): ``dense_frac`` moves the dense-path threshold
@@ -300,6 +301,11 @@ def search_batch_fused(
     top = TopK(scores=jnp.full((n_batch, k), -jnp.inf, jnp.float32),
                rows=jnp.full((n_batch, k), -1, jnp.int32))
 
+    def walk_steps(n_slots):          # the kernel's grid steps, when traced
+        return ops.block_mips_steps(
+            n_slots, n_batch, meta.d, k=k, page_rows=meta.page_rows,
+            use_pallas=use_pallas) if obs else 0
+
     scores_cache = None
     with _span("pull_mask_round1", active=obs, layer="pull"):
         mask_np = np.asarray(mask_r1)
@@ -318,7 +324,8 @@ def search_batch_fused(
         # (B, n_pad) product so the compensation round needs NO new matmul.
         want_scores = dense and not ops._resolve(use_pallas)
         with _span("verify_round1", active=obs, layer="dispatch",
-                   slots=len(slots), union=n_union) as sp:
+                   slots=len(slots), union=n_union,
+                   steps=walk_steps(len(slots))) as sp:
             top, pages1, cand1, done_a, scores_cache = _verify(
                 arrays, queries, jnp.asarray(slots), jnp.asarray(sel),
                 top.scores, top.rows, c_half, k, meta.page_rows, dense,
@@ -348,7 +355,8 @@ def search_batch_fused(
     else:
         slots, sel, lost_np, dense, n_union = plan
         with _span("verify_round2", active=obs, layer="dispatch",
-                   slots=len(slots), union=n_union) as sp:
+                   slots=len(slots), union=n_union,
+                   steps=walk_steps(len(slots))) as sp:
             if scores_cache is not None:
                 if obs:
                     _metrics.counter("fused.rounds_cached").inc()

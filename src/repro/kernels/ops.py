@@ -23,6 +23,7 @@ from . import ref
 from .binary_probe import binary_probe_lb as _binary_probe_pallas
 from .block_mips import MAX_K as BLOCK_MIPS_MAX_K
 from .block_mips import block_mips as _block_mips_pallas
+from .block_mips import walk_steps as _block_mips_steps
 from .block_mips import sketch_scores as _sketch_scores_pallas
 from .decode_attention import decode_attention as _decode_attention_pallas
 from .mips_topk import mips_score as _mips_score_pallas
@@ -66,6 +67,15 @@ def block_mips(x, valid, q, slots, sel, init_scores, init_rows, c_half, *,
                               interpret=_interpret())
 
 
+def block_mips_steps(n_slots: int, b: int, d: int, *, k: int, page_rows: int,
+                     use_pallas: Optional[bool] = None) -> int:
+    """Grid steps `block_mips` takes over an ``n_slots`` walk (chained
+    calls summed); 0 where the walk takes the oracle, which has no grid."""
+    if not _resolve(use_pallas) or k > BLOCK_MIPS_MAX_K:
+        return 0
+    return _block_mips_steps(n_slots, b, d, k, page_rows)
+
+
 def sketch_scores(q, sk_mu, codebooks, codes, *,
                   use_pallas: Optional[bool] = None):
     """Estimated block scores for the verification prefilter: (B, NB) with
@@ -105,10 +115,9 @@ def mips_topk(x, q, valid, k: int, *, use_pallas: Optional[bool] = None,
     elsewhere — previously this defaulted to True, silently putting off-TPU
     callers on interpret mode while `mips_score` did not). On the Pallas
     path the scan is routed through the fused `block_mips` kernel: the
-    corpus is walked ``page_rows`` rows at a time with a streaming top-k,
-    so no (R, B) score matrix is materialized. ``page_rows`` is kept small
-    because the kernel's rank-select holds (B, k+page_rows)^2 comparison
-    cubes in VMEM. On the fused route rows with fewer than k valid
+    corpus is walked ``page_rows`` rows a page, several pages a grid step,
+    with a streaming top-k, so no (R, B) score matrix is materialized. On
+    the fused route rows with fewer than k valid
     candidates come back as -1 with -inf scores; `mips_score` ``block_*``
     kwargs are score-matrix tile sizes, so passing any routes through the
     score+`lax.top_k` pair instead (there they keep their meaning —

@@ -63,33 +63,87 @@ def test_ops_wrappers_route(rng):
     np.testing.assert_allclose(np.asarray(top), want, atol=1e-4)
 
 
-@pytest.mark.parametrize("nb,p,d,b,k,ns", [
-    (12, 8, 32, 5, 4, 8), (30, 16, 64, 9, 10, 16), (6, 8, 48, 3, 12, 6),
-    (20, 8, 128, 17, 1, 4)])
-def test_block_mips_sweep(rng, nb, p, d, b, k, ns):
+@pytest.mark.parametrize("nb,p,d,b,k,ns,case", [
+    pytest.param(12, 8, 32, 5, 4, 8, "random", id="12-8-32-5-4-8"),
+    pytest.param(30, 16, 64, 9, 10, 16, "random", id="30-16-64-9-10-16"),
+    pytest.param(6, 8, 48, 3, 12, 6, "random", id="6-8-48-3-12-6"),
+    pytest.param(20, 8, 128, 17, 1, 4, "random", id="20-8-128-17-1-4"),
+    # 21 slots: no multiple of the 16-page step, pad slots mid-list; d = 300
+    pytest.param(40, 3, 300, 4, 10, 21, "pad_mid", id="pad-mid-page3"),
+    # every valid row a hit: the Condition-A stop falls inside a step
+    pytest.param(40, 8, 128, 6, 2, 33, "stop", id="stop-in-step-page8"),
+    pytest.param(40, 3, 300, 6, 3, 24, "stop", id="stop-in-step-page3"),
+    # small-integer data: exact equal scores across pages and steps
+    pytest.param(40, 3, 300, 3, 5, 24, "ties", id="ties-page3"),
+    pytest.param(24, 8, 16, 5, 12, 20, "ties", id="ties-page8"),
+    # selected pages whose rows are all invalid
+    pytest.param(24, 8, 16, 4, 6, 20, "invalid_page", id="invalid-page8"),
+    pytest.param(40, 3, 300, 4, 6, 24, "invalid_page", id="invalid-page3"),
+    # one-row pages: one 8-row tile covers each (d >= 1024 in the layout)
+    pytest.param(40, 1, 64, 4, 5, 24, "random", id="page1"),
+    # a walk longer than one call's slot list: a chain of calls
+    pytest.param(60, 3, 300, 5, 10, 50, "chain", id="chain-page3"),
+])
+def test_block_mips_sweep(rng, monkeypatch, nb, p, d, b, k, ns, case):
     """Fused block-sparse verification kernel (interpret) vs jnp oracle:
     streaming top-k, per-slot hit counts, Condition-A page/candidate
-    accounting — with padding slots, invalid rows and carried-in tops."""
-    from repro.kernels.block_mips import block_mips
+    accounting — with padding slots, invalid rows and carried-in tops, over
+    walks of several pages per grid step."""
+    from repro.kernels import block_mips as bm
 
     n = nb * p
-    x = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
-    valid = jnp.asarray(rng.rand(n) > 0.15)
-    q = jnp.asarray(rng.standard_normal((b, d)), jnp.float32)
+    x = rng.standard_normal((n, d))
+    valid = rng.rand(n) > 0.15
+    q = rng.standard_normal((b, d))
     blocks = np.sort(rng.permutation(nb)[: ns - 1])
-    slots = jnp.asarray(np.concatenate([blocks, [0]]), jnp.int32)  # pad slot
-    sel = jnp.asarray(rng.rand(b, ns) > 0.4).at[:, ns - 1].set(False)
-    init_s = jnp.sort(jnp.asarray(rng.standard_normal((b, k)), jnp.float32),
-                      axis=1)[:, ::-1]
-    init_r = jnp.asarray(rng.randint(0, n, (b, k)), jnp.int32)
-    c_half = jnp.asarray(rng.standard_normal(b) * 2, jnp.float32)
+    slots = np.concatenate([blocks, [0]])                  # pad slot last
+    sel = rng.rand(b, ns) > 0.4
+    sel[:, ns - 1] = False
+    init_s = -np.sort(-rng.standard_normal((b, k)), axis=1)
+    init_r = rng.randint(0, n, (b, k))
+    c_half = rng.standard_normal(b) * 2
+    if case == "pad_mid":
+        for j in (3, 9, 10, 17):
+            slots[j], sel[:, j] = 0, False
+    elif case == "stop":
+        c_half[:] = -1e9
+        init_s[:], init_r[:] = -np.inf, -1
+    elif case == "ties":
+        # page 0 repeated as page 1 (same step) and as the last page (a
+        # later step), all three walked
+        x = rng.randint(-1, 2, (n, d))
+        x[p:2 * p] = x[(nb - 1) * p:] = x[:p]
+        slots[:ns - 1] = np.concatenate(
+            [[0, 1], np.sort(rng.permutation(np.arange(2, nb - 1))[:ns - 4]),
+             [nb - 1]])
+        q = rng.randint(-1, 2, (b, d))
+        init_s = -np.sort(-rng.randint(-3, 4, (b, k)), axis=1)
+    elif case == "invalid_page":
+        for s in slots[[1, 4, 5]]:
+            valid[s * p:(s + 1) * p] = False
+    elif case == "chain":
+        walk = bm._walk
+        calls = []
+        # 16 slots a call: the slot list and one window word each
+        monkeypatch.setattr(bm, "SMEM_BUDGET", 16 * 2 * 4)
+        monkeypatch.setattr(bm, "_walk", lambda *a, **kw: calls.append(1)
+                            or walk(*a, **kw))
+    args = (jnp.asarray(x, jnp.float32), jnp.asarray(valid),
+            jnp.asarray(q, jnp.float32), jnp.asarray(slots, jnp.int32),
+            jnp.asarray(sel), jnp.asarray(init_s, jnp.float32),
+            jnp.asarray(init_r, jnp.int32), jnp.asarray(c_half, jnp.float32))
 
-    got = block_mips(x, valid, q, slots, sel, init_s, init_r, c_half,
-                     k=k, page_rows=p, interpret=True)
-    want = ref.block_mips_ref(x, valid, q, slots, sel, init_s, init_r, c_half,
-                              k=k, page_rows=p)
+    got = bm.block_mips(*args, k=k, page_rows=p, interpret=True)
+    want = ref.block_mips_ref(*args, k=k, page_rows=p)
+    assert bm.pages_per_step(ns, b, d, k, p) > 1
+    if case == "chain":
+        assert len(calls) > 1
+    if case == "stop":
+        assert 0 < int(got[3].max()) < int(sel.sum(axis=1).max())
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
                                atol=1e-4)
+    if case == "ties":                                     # exact scores
+        np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
     for name, g, w in zip(("top_r", "cnt", "pages", "cand"),
                           got[1:], want[1:]):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w),

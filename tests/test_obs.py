@@ -17,6 +17,7 @@ from repro.core.promips import ProMIPS
 from repro.core.runtime import RuntimeConfig
 from repro.core.sharded import MutableShardedProMIPS
 from repro.core import search_fused as sf
+from repro.kernels.block_mips import walk_steps
 from repro.obs import metrics, trace
 from repro.stream.mutable import MutableProMIPS
 
@@ -268,14 +269,23 @@ def test_verify_round_stats_equal_plan_tile_result(searcher, corpus,
 
     monkeypatch.setattr(sf, "_plan_tile", spy)
     trace.enable()
-    for rt in (searcher.runtime, capped(searcher)):
+    # the oracle walks with no grid (0 steps); the kernel (interpreted
+    # here) walks several pages per grid step
+    for rt in (searcher.runtime, capped(searcher),
+               capped(searcher, use_pallas=True)):
         searcher.search(q, runtime=rt)
     rounds = [r for r in trace.spans() if r["name"].startswith("verify_round")]
-    assert len(rounds) == len(plans) == 4
-    for rec, (union, plan) in zip(rounds, plans):
+    assert len(rounds) == len(plans) == 6
+    meta = searcher.pm.meta
+    for i, (rec, (union, plan)) in enumerate(zip(rounds, plans)):
         slots, _, _, _, n_union = plan
+        steps = walk_steps(len(slots), len(q), meta.d, searcher.guarantee.k,
+                           meta.page_rows) if i >= 4 else 0
         assert n_union == union
-        assert rec["stats"] == {"slots": len(slots), "union": union}
+        assert rec["stats"] == {"slots": len(slots), "union": union,
+                                "steps": steps}
+    assert all(0 < r["stats"]["steps"] < r["stats"]["slots"]
+               for r in rounds[4:])
 
 
 # -- metrics registry --------------------------------------------------------

@@ -4,7 +4,8 @@ Nothing runs: the kernels are lowered and compiled by Mosaic for one chip of
 a described (not attached) ``v5e:2x2`` topology, which refuses what
 interpret mode accepts — misaligned blocks, too much VMEM or SMEM. Shapes:
 the LARGE_N benchmark point (n=100k, d=128, 8-row pages) and the paper's
-Yahoo! Music corpus (n=624,961, d=300, 3-row pages), a 64-query batch. The
+Yahoo! Music corpus (n=624,961, d=300, 3-row pages), a 64-query batch, and
+its Netflix corpus (n=17,770) at a 16-query batch. The
 selection stages and the jnp verify oracle also compile at Yahoo size,
 where XLA:TPU once spent minutes on them.
 """
@@ -16,7 +17,9 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.block_mips import MAX_K, MAX_SLOTS, block_mips, sketch_scores
+from repro.kernels import ops
+from repro.kernels.block_mips import (MAX_K, _slots_per_call, block_mips,
+                                     pages_per_step, sketch_scores)
 from repro.kernels.mips_topk import mips_score
 
 B = 64
@@ -24,6 +27,7 @@ HBM_BYTES = 16e9          # one v5e chip
 # (n_pad, d, page_rows): LARGE_N and Yahoo! Music (n rounded up to pages)
 LARGE_N = (100_000, 128, 8)
 YAHOO = (624_963, 300, 3)
+NETFLIX = (17_772, 300, 3)
 
 
 @pytest.fixture(scope="module")
@@ -55,15 +59,16 @@ def _compile(fn, one_chip, *shapes, mosaic=True):
     return compiled
 
 
-@pytest.mark.parametrize("n_pad,d,page_rows", [LARGE_N, YAHOO],
-                         ids=["large_n", "yahoo"])
+@pytest.mark.parametrize("n_pad,d,page_rows,b", [
+    LARGE_N + (B,), YAHOO + (B,), NETFLIX + (16,)],
+    ids=["large_n", "yahoo", "netflix_b16"])
 @pytest.mark.parametrize("k", [10, MAX_K])
-def test_block_mips_compiles(one_chip, n_pad, d, page_rows, k):
+def test_block_mips_compiles(one_chip, n_pad, d, page_rows, b, k):
     ns = 1024
     _compile(lambda *a: block_mips(*a, k=k, page_rows=page_rows), one_chip,
              ((n_pad, d), jnp.float32), ((n_pad,), jnp.bool_),
-             ((B, d), jnp.float32), ((ns,), jnp.int32), ((B, ns), jnp.bool_),
-             ((B, k), jnp.float32), ((B, k), jnp.int32), ((B,), jnp.float32))
+             ((b, d), jnp.float32), ((ns,), jnp.int32), ((b, ns), jnp.bool_),
+             ((b, k), jnp.float32), ((b, k), jnp.int32), ((b,), jnp.float32))
 
 
 def test_block_mips_dense_walk_compiles(one_chip):
@@ -71,12 +76,55 @@ def test_block_mips_dense_walk_compiles(one_chip):
     SMEM slot list holds, so the walk is a chain of calls."""
     n_pad, d, page_rows = YAHOO
     ns = n_pad // page_rows
-    assert ns > MAX_SLOTS
+    assert ns > _slots_per_call(page_rows)
+    # the walk takes several pages per grid step at this size
+    assert pages_per_step(ns, B, d, 10, page_rows) > 1
     _compile(lambda *a: block_mips(*a, k=10, page_rows=page_rows), one_chip,
              ((n_pad, d), jnp.float32), ((n_pad,), jnp.bool_),
              ((B, d), jnp.float32), ((ns,), jnp.int32), ((B, ns), jnp.bool_),
              ((B, 10), jnp.float32), ((B, 10), jnp.int32),
              ((B,), jnp.float32))
+
+
+def test_mips_topk_block_route_compiles(one_chip, monkeypatch):
+    """`ops.mips_topk` walks the whole corpus through `block_mips` at its own
+    32-row pages: 32 window rows, so two validity words a slot and fewer
+    slots a call."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    n = 600_000
+    assert -(-n // 32) > _slots_per_call(32)      # a chain of calls
+    _compile(lambda x, q, v: ops.mips_topk(x, q, v, 10, use_pallas=True),
+             one_chip, ((n, 300), jnp.float32), ((B, 300), jnp.float32),
+             ((n,), jnp.bool_))
+
+
+def test_sharded_fused_search_compiles(one_chip, monkeypatch):
+    """The in-graph fused driver under shard_map, one shard per chip of the
+    described 2x2 v5e: every tile bucket's `block_mips` compiles there."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from repro.core import RuntimeConfig
+    from repro.core.sharded import _sharded_search_fn, build_sharded
+    from repro.data.synthetic import mf_factors
+
+    from jax.experimental import topologies
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = Mesh(np.asarray(topo.devices), ("model",))
+    sh = build_sharded(mf_factors(4000, 300, 32, seed=0), 4, m=8, seed=0)
+    cfg = RuntimeConfig(mode="two_phase", verification="fused",
+                        norm_adaptive=True, use_pallas=True, k=10)
+    shard = NamedSharding(mesh, PartitionSpec("model"))
+    arrays = type(sh.arrays)(**{
+        f: jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype,
+                                sharding=shard)
+        for f, a in sh.arrays._asdict().items()})
+    q = jax.ShapeDtypeStruct((16, 300), jnp.float32,
+                             sharding=NamedSharding(mesh, PartitionSpec()))
+    fn = _sharded_search_fn(sh.meta, 10, mesh, "model", cfg)
+    text = fn.lower(arrays, q).compile().as_text()
+    assert "tpu_custom_call" in text and "block_mips" in text
 
 
 @pytest.mark.parametrize("d,m,n_blocks", [(128, 16, 12_500), (300, 15, 208_321)],
